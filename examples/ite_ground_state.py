@@ -12,7 +12,10 @@ buys at fixed D.
 """
 import argparse
 
+import jax.numpy as jnp
+
 from repro.core import bmps as B
+from repro.core import planner
 from repro.core.ite import ite_run, ite_statevector
 from repro.core.observable import j1j2_hamiltonian
 from repro.core.peps import FullUpdate, QRUpdate, computational_zeros
@@ -38,7 +41,11 @@ def main():
                          "measurements; the final state is always re-"
                          "measured with BOTH engines at equal chi and the "
                          "error gap printed")
+    ap.add_argument("--dtype", choices=("complex128", "complex64"),
+                    default="complex128",
+                    help="site-tensor dtype (TPUs run complex64 only)")
     args = ap.parse_args()
+    planner.enable_compile_cache()
 
     n = args.grid
     obs = j1j2_hamiltonian(n, n)  # J1=1.0, J2=0.5, h=0.2 (paper Fig. 13)
@@ -64,7 +71,8 @@ def main():
                   f"(err {abs(energy-e_ref)/abs(e_ref):.2e})")
 
         res = ite_run(
-            computational_zeros(n, n), obs, args.tau, args.steps,
+            computational_zeros(n, n, dtype=jnp.dtype(args.dtype)), obs,
+            args.tau, args.steps,
             update=updates[name],
             contract=B.BMPS(args.chi, RandomizedSVD(niter=2, oversample=4),
                             engine=args.engine),

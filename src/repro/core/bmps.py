@@ -301,8 +301,10 @@ def final_row_amplitudes(env, row_sites, bits, log_scale=0.0) -> jnp.ndarray:
         return run
 
     fn = planner.fused_fn("serve_close", sig, build)
+    # log_scale in the sites' own real precision: a float64 scalar would put
+    # emulated float64 arithmetic into a complex64 program on a TPU
     return fn(list(env), list(row_sites), bits,
-              jnp.asarray(log_scale, dtype=jnp.float64))
+              jnp.asarray(log_scale, dtype=jnp.finfo(dtype).dtype))
 
 
 def amplitudes(state, bits_batch, option: BMPS, key=None) -> jnp.ndarray:

@@ -53,6 +53,7 @@ here first, then refined in the neighborhood-environment metric.
 from __future__ import annotations
 
 import dataclasses
+from functools import partial
 from typing import Optional, Sequence, Tuple
 
 import jax
@@ -69,27 +70,70 @@ def _apply_cutoff(s: jnp.ndarray, cutoff: float) -> jnp.ndarray:
     return jnp.where(s >= cutoff * s[0], s, 0.0)
 
 
+def _scaled_svd(mat: jnp.ndarray, rank: int):
+    """Rank-``rank`` SVD of ``mat`` after scaling it to unit range and
+    zeroing the entries below the dtype's resolution relative to its
+    largest entry, with ``s`` and ``vh`` recomputed from ``u``.
+
+    The scale is a power of two (exact in binary floating point, undone on
+    ``s``).  The zeroed entries perturb ``mat`` by less than the SVD's own
+    backward error.  Without them, the complex64 environment sweeps of a
+    full-update ITE came out non-finite on a TPU v5e (its SVD on
+    rank-deficient padded matrices); with them the same run is finite.
+
+    ``u`` is the SVD's; the rows of ``u^H a`` give ``s`` (their norms) and
+    ``vh`` (their directions), so ``u @ diag(s) @ vh == u u^H a`` to
+    rounding however well the SVD paired its left and right vectors.  A
+    TPU's ``jnp.linalg.svd`` (QDWH polar factor, then eigh; JAX's code for
+    it, run on a CPU) reproduces the complex64 matrices of a 4x4 RQC
+    amplitude sweep only to 5.5e-6, while its ``u u^H a`` is within 6e-7;
+    on a v5e the served amplitudes were 2.1e-5 off the statevector without
+    the projection and 1.9e-6 with it (PERF.md).  Both changes are
+    forward-only: the derivative stays ``svd_reg``'s."""
+    real = jnp.finfo(mat.dtype).dtype
+    amax = jax.lax.stop_gradient(jnp.max(jnp.abs(mat)))
+    _, exp = jnp.frexp(amax)
+    exp = jnp.where(amax > 0, exp, 0)
+    a = mat * jnp.ldexp(jnp.ones((), real), -exp)
+    # the flush is forward-only: the gradient still reaches the entries
+    # that are zero at a degenerate point
+    a = a - jax.lax.stop_gradient(
+        jnp.where(jnp.abs(a) < jnp.finfo(real).eps, a, jnp.zeros((), a.dtype)))
+    u, s, vh = svd_reg(a)
+    u, s, vh = u[:, :rank], s[:rank], vh[:rank]
+    w = jax.lax.stop_gradient(u.conj().T @ a)
+    norm = jnp.linalg.norm(w, axis=1)
+    safe = jnp.where(norm > 0, norm, 1.0)
+    w = jnp.where(norm[:, None] > 0, w / safe[:, None].astype(w.dtype), vh)
+    s = s + jax.lax.stop_gradient(norm - s)
+    vh = vh + jax.lax.stop_gradient(w - vh)
+    return u, s * jnp.ldexp(jnp.ones((), real), exp), vh
+
+
 @dataclasses.dataclass(frozen=True)
 class DirectSVD:
-    """Explicitly contract theta, then truncated LAPACK SVD."""
+    """Explicitly contract theta, then truncated dense SVD."""
     cutoff: float = 0.0
 
     def __call__(self, op: ImplicitOperator, rank: int, key=None):
         theta = op.dense()
         m, n = op.row_size, op.col_size
         rank = min(rank, m, n)
-        mat = theta.reshape(m, n)
-        # svd_reg: forward bit-identical to jnp.linalg.svd, JVP regularized
-        # for (near-)degenerate / zero singular values (core/svd_grad.py) —
-        # this seam is what jax.grad(vqe_energy_peps) differentiates through.
-        u, s, vh = svd_reg(mat)
-        u, s, vh = u[:, :rank], s[:rank], vh[:rank]
-        s = _apply_cutoff(s, self.cutoff)
-        return (
-            u.reshape(op.row_shape + (rank,)),
-            s,
-            vh.reshape((rank,) + op.col_shape),
-        )
+        u, s, vh = _truncated_svd(theta, m, n, rank, tuple(op.row_shape),
+                                  tuple(op.col_shape), self.cutoff)
+        return u, s, vh
+
+
+@partial(jax.jit, static_argnums=(1, 2, 3, 4, 5, 6))
+def _truncated_svd(theta, m, n, rank, row_shape, col_shape, cutoff):
+    # One program per shape: reshape, SVD, truncation.  svd_reg's JVP is
+    # regularized for (near-)degenerate / zero singular values
+    # (core/svd_grad.py) — this seam is what jax.grad(vqe_energy_peps)
+    # differentiates through.
+    u, s, vh = _scaled_svd(theta.reshape(m, n), rank)
+    s = _apply_cutoff(s, cutoff)
+    return (u.reshape(row_shape + (rank,)), s,
+            vh.reshape((rank,) + col_shape))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -97,7 +141,8 @@ class RandomizedSVD:
     """Implicit randomized SVD (paper Alg. 4).
 
     ``gram_final`` replaces the paper's dense k x Ncol final SVD with a
-    Gram-QR + local k x k SVD (beyond-paper; see EXPERIMENTS.md SSPerf).
+    Gram-QR whose eigendecomposition gives the SVD (beyond-paper; see
+    EXPERIMENTS.md SSPerf).
 
     ``fused`` (default) runs the whole solve as one jit-compiled function
     per network signature, reused across all structurally-identical

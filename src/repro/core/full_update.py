@@ -228,7 +228,14 @@ def _pair(a, b):
 
 def _regularized_solve(m, rhs, eps):
     d = m.shape[0]
-    reg = eps * (jnp.trace(m).real / d + jnp.finfo(m.real.dtype).tiny)
+    # The ridge must sit well above the dtype's rounding, or the rounding
+    # noise in the rank-deficient directions of a low-rank bond grows sweep
+    # over sweep: in complex64 the default 1e-12 vanishes against trace/d
+    # and the first product-state bonds of an ITE solve to Inf/NaN.  The
+    # floor (100 ulps) never binds in complex128.
+    finfo = jnp.finfo(m.real.dtype)
+    rel = max(eps, 100 * float(finfo.eps))
+    reg = rel * (jnp.trace(m).real / d + finfo.tiny)
     return jnp.linalg.solve(m + reg * jnp.eye(d, dtype=m.dtype), rhs)
 
 
@@ -314,14 +321,14 @@ def full_update_bond(state, g, s0: Tuple[int, int], s1: Tuple[int, int],
     state; the bond fidelity is appended to the module log (see
     :func:`drain_fidelities`)."""
     (i0, j0), (i1, j1) = s0, s1
+    g = jnp.asarray(g, dtype=state.dtype)
     # canonical orientations: left->right or top->bottom
     if (i0 == i1 and j1 == j0 - 1) or (j0 == j1 and i1 == i0 - 1):
-        gt = jnp.transpose(jnp.asarray(g), (1, 0, 3, 2))
+        gt = jnp.transpose(g, (1, 0, 3, 2))
         return full_update_bond(state, gt, s1, s0, update, key, envs)
     if not ((i0 == i1 and j1 == j0 + 1) or (j0 == j1 and i1 == i0 + 1)):
         raise ValueError(f"sites {s0}, {s1} are not adjacent")
 
-    g = jnp.asarray(g, dtype=state.dtype)
     key, env_key, seed_key = jax.random.split(key, 3)
     if envs is None or not envs_compatible(state, s0, s1, envs):
         # missing, or shape-stale (a bond grew since the cached sweep —

@@ -146,6 +146,46 @@ def tall_project(a: jnp.ndarray, mat: jnp.ndarray,
     return _dispatch.dispatch("tall_apply", a, mat, n_small)
 
 
+def gram_factors(a: jnp.ndarray, n_small: int):
+    """The Gram trick's factors ``(q, x, sqrt_lam)`` of :func:`gram_qr`:
+    ``a == q . R`` with ``R = diag(sqrt_lam) @ x^H`` over the small modes,
+    ``x`` unitary (``nsmall x nsmall``) and ``sqrt_lam`` the clamped square
+    roots of the Gram eigenvalues, in eigh's (ascending) order.  ``R^H =
+    x diag(sqrt_lam)`` is an SVD up to the column order, which
+    :func:`repro.core.rsvd.randomized_svd` uses instead of a dense SVD."""
+    big_shape = a.shape[: a.ndim - n_small]
+    nbig = 1
+    for s in big_shape:
+        nbig *= s
+    nsmall = 1
+    for s in a.shape[a.ndim - n_small:]:
+        nsmall *= s
+
+    big_axes = tuple(range(a.ndim - n_small))
+    # G_{cc'} = sum_big conj(A)_{big,c} A_{big,c'} — contraction, no reshape of A
+    # (or the Pallas streaming-Gram kernel when the operand qualifies).
+    g_mat = _gram_matrix(a, big_axes, nbig, nsmall)  # small, local
+    # eigh_reg == jnp.linalg.eigh forward; its regularized JVP keeps the
+    # gradient finite when G is rank-deficient (clusters of exactly zero
+    # eigenvalues — the squared singular values of a padded bond).  The
+    # eps clamp below additionally stops the gradient through the noise
+    # directions (jnp.maximum passes the gradient to the clamp side).
+    lam, x = eigh_reg(g_mat)
+    # The clamp is relative to the largest eigenvalue (tiny only guards the
+    # all-zero matrix): an absolute floor turned every Gram spectrum below 1,
+    # i.e. every small-scale network, into clamp noise.
+    eps = jnp.maximum(_eps_for(a.dtype) * jnp.max(jnp.abs(lam)),
+                      jnp.finfo(lam.real.dtype).tiny)
+    lam = jnp.maximum(lam.real, eps)
+    sqrt_lam = jnp.sqrt(lam)
+    p_mat = x / sqrt_lam[None, :]                      # P = R^{-1} = X L^{-1/2}
+    # Q = A P (contraction over the small modes — big modes untouched;
+    # Pallas-dispatched via the "tall_apply" site, dense path identical to
+    # the pre-kernel tensordot).
+    q = tall_project(a, p_mat, n_small).reshape(a.shape)
+    return q, x, sqrt_lam
+
+
 def gram_qr(a: jnp.ndarray, n_small: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """QR-equivalent factorization A = Q @ R via the Gram trick (Alg. 5).
 
@@ -161,36 +201,10 @@ def gram_qr(a: jnp.ndarray, n_small: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
     No reshape touches the big modes: G = A*A is formed by a contraction, the
     eigendecomposition happens on the small G only.
     """
-    big_shape = a.shape[: a.ndim - n_small]
     small_shape = a.shape[a.ndim - n_small:]
-    nbig = 1
-    for s in big_shape:
-        nbig *= s
-    nsmall = 1
-    for s in small_shape:
-        nsmall *= s
-
-    big_axes = tuple(range(a.ndim - n_small))
-    # G_{cc'} = sum_big conj(A)_{big,c} A_{big,c'} — contraction, no reshape of A
-    # (or the Pallas streaming-Gram kernel when the operand qualifies).
-    g_mat = _gram_matrix(a, big_axes, nbig, nsmall)  # small, local
-    # eigh_reg == jnp.linalg.eigh forward; its regularized JVP keeps the
-    # gradient finite when G is rank-deficient (clusters of exactly zero
-    # eigenvalues — the squared singular values of a padded bond).  The
-    # eps clamp below additionally stops the gradient through the noise
-    # directions (jnp.maximum passes the gradient to the clamp side).
-    lam, x = eigh_reg(g_mat)
-    eps = _eps_for(a.dtype) * jnp.maximum(jnp.max(jnp.abs(lam)), 1.0)
-    lam = jnp.maximum(lam.real, eps)
-    sqrt_lam = jnp.sqrt(lam)
+    q, x, sqrt_lam = gram_factors(a, n_small)
     r_mat = (sqrt_lam[:, None] * x.conj().T)           # R = sqrt(L) X^H
-    p_mat = x / sqrt_lam[None, :]                      # P = R^{-1} = X L^{-1/2}
-    # Q = A P (contraction over the small modes — big modes untouched;
-    # Pallas-dispatched via the "tall_apply" site, dense path identical to
-    # the pre-kernel tensordot).
-    q = tall_project(a, p_mat, n_small).reshape(a.shape)
-    r = r_mat.reshape(small_shape + small_shape)
-    return q, r
+    return q, r_mat.reshape(small_shape + small_shape)
 
 
 def orthogonalize_cols(t: jnp.ndarray) -> jnp.ndarray:

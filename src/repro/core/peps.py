@@ -137,11 +137,15 @@ def random_peps(nrow: int, ncol: int, bond: int, key, phys: int = 2,
             r = 1 if j == ncol - 1 else bond
             key, k1, k2 = jax.random.split(key, 3)
             shape = (phys, u, l, d, r)
+            # draw in the target precision: a complex64 state never passes
+            # through complex128 (which a TPU cannot run)
+            real = jnp.finfo(dtype).dtype
             if jnp.issubdtype(jnp.dtype(dtype), jnp.complexfloating):
-                t = (jax.random.normal(k1, shape) + 1j * jax.random.normal(k2, shape))
+                t = jax.lax.complex(jax.random.normal(k1, shape, real),
+                                    jax.random.normal(k2, shape, real))
             else:
-                t = jax.random.normal(k1, shape)
-            row.append(t.astype(dtype) / np.sqrt(np.prod(shape)))
+                t = jax.random.normal(k1, shape, real)
+            row.append((t / np.sqrt(np.prod(shape), dtype=real)).astype(dtype))
         sites.append(row)
     return PEPS(sites)
 
@@ -195,7 +199,8 @@ class FullUpdate:
     env_svd:    einsumsvd engine for the environment sweeps.
     als_iters:  fixed number of ALS sweeps (static; part of the jit
                 signature).
-    als_eps:    relative Tikhonov regularization of the ALS normal matrices.
+    als_eps:    relative Tikhonov regularization of the ALS normal matrices
+                (floored at 100 ulps of the state's dtype).
     positive:   hermitize + eigenvalue-clamp the bond environment (the
                 gauge/positive fix; strongly recommended).
     env_refresh_every: in ``ite.ite_run``, refresh the cached row

@@ -271,6 +271,33 @@ def load_path_cache(path: str) -> int:
     return installed
 
 
+#: Where :func:`enable_compile_cache` keeps JAX's persistent compilation
+#: cache when ``JAX_COMPILATION_CACHE_DIR`` is unset: a fixed directory in
+#: the checkout (listed in ``.gitignore``).  The path is part of what lets a
+#: later process find the entries, so it must not move between runs.
+COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.abspath(__file__)), os.pardir, os.pardir,
+    os.pardir, "_build", "jax_cache")
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for this process.
+
+    Entry points call this once at start-up; importing the library never
+    does.  ``JAX_COMPILATION_CACHE_DIR`` wins when it is set (JAX reads it
+    itself, and nothing else is set here); otherwise the cache lives in
+    :data:`COMPILE_CACHE_DIR`.  Most executables of this library compile in
+    well under a second, below JAX's default one-second write threshold, so
+    the threshold is dropped to zero and every executable is cached.
+    Returns the cache directory."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = os.path.normpath(COMPILE_CACHE_DIR)
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return path
+
+
 _INT_LABELS = "abcdefghijklmnopqrstuvwxyzABCDEFGHIJKLMNOPQRSTUVWXYZ"
 
 

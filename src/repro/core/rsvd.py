@@ -132,8 +132,9 @@ def randomized_svd(
     line 7 runs a dense SVD of the k x Ncol matrix ``P^H A`` — on a
     distributed backend that is a large matricized factorization, exactly
     what Alg. 5 exists to avoid.  Instead we Gram-QR the tall ``T = A^H P``
-    (all-GEMM, reshape-free) and SVD only its k x k R factor locally:
-    ``A ~= P (Q_t R_t)^H = (P U) S (Q_t V)^H``.
+    (all-GEMM, reshape-free), whose k x k R factor is ``diag(sqrt(L)) X^H``
+    with ``X`` unitary: ``A ~= P (Q_t R_t)^H = (P X) sqrt(L) Q_t^H`` is the
+    SVD, read off the Gram eigendecomposition with no dense SVD at all.
     """
     if key is None:
         key = jax.random.PRNGKey(0)
@@ -160,18 +161,18 @@ def randomized_svd(
     # B = P^H A  (as a k x col matrix), computed via A^H P (one more pass).
     t = op.rmatvecs(p)                               # col_shape + (k,)
     if gram_final:
-        from repro.core.orthogonalize import gram_qr
-        q_t, r_t = gram_qr(t, 1)                     # q_t: col+(k,), r_t: (k,k)
-        # A ~= P T^H = P (q_t r_t)^H = P r_t^H q_t^H
-        # (svd_reg == jnp.linalg.svd forward; regularized JVP.)
-        u_small, s, vh_small = svd_reg(r_t.conj().T)          # k x k, local
-        u_small, s, vh_small = u_small[:, :rank], s[:rank], vh_small[:rank]
-        # Final projections: tall operand x small matrix — the tall-apply
+        from repro.core.orthogonalize import gram_factors
+        # T = q_t R with R^H = x diag(sqrt_lam), x unitary and sqrt_lam in
+        # eigh's ascending order, so A ~= P T^H = (P x) diag(sqrt_lam) q_t^H
+        # is an SVD once the columns are reversed: no dense k x k SVD (on a
+        # TPU, jnp.linalg.svd inside the SPMD superstep's shard_map crashes
+        # the compiler).
+        q_t, x, sqrt_lam = gram_factors(t, 1)        # q_t: col+(k,)
+        s = sqrt_lam[::-1][:rank]
+        # Final projection: tall operand x small matrix — the tall-apply
         # kernel site (dense path is the exact pre-kernel tensordot).
-        u = tall_project(p, u_small, 1)              # row_shape+(rank,)
-        # v = (q_t @ vh_small^H)^H: rank x col
-        v = tall_project(q_t.conj(), vh_small.T, 1)  # col_shape+(rank,)
-        v = jnp.moveaxis(v, -1, 0)
+        u = tall_project(p, x[:, ::-1][:, :rank], 1)  # row_shape+(rank,)
+        v = jnp.moveaxis(q_t[..., ::-1][..., :rank].conj(), -1, 0)
         return u, s, v
     b = t.conj().reshape(op.col_size, k).T           # (k, ncol)
     u_small, s, vh = svd_reg(b)

@@ -102,7 +102,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core import planner
 from repro.core.bmps import _keys, zipup_block, zipup_block_twolayer
-from repro.launch.mesh import col_mesh, shard_map
+from repro.launch.mesh import col_mesh
 
 _AXIS = "col"
 
@@ -512,8 +512,8 @@ def _build_wave(kernel, chi, svd, plan: RowPlan, R: int, collect: bool,
     nsites = kernel.nsites
     in_specs = (P(_AXIS), P(None, _AXIS)) + (P(None, _AXIS),) * nsites
     out_specs = (P(_AXIS), P(None, _AXIS)) if collect else (P(_AXIS),)
-    return jax.jit(shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs))
+    return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                                 out_specs=out_specs, check_vma=False))
 
 
 def _get_fn(kernel, chi, svd, plan: RowPlan, R: int, collect: bool, devices):

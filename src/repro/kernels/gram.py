@@ -18,10 +18,14 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
+# Block index of an untiled axis.  A bare ``0`` traces as int64 once x64 is
+# enabled (``import repro.core`` enables it), and Mosaic rejects an index map
+# that returns mixed int32/int64 indices.
+_ZERO = np.int32(0)
 
 
 def _gram_kernel(a_ref, g_ref, acc_ref, *, compute):
@@ -58,11 +62,11 @@ def _gram(a: jnp.ndarray, bm: int, interpret: bool, compute) -> jnp.ndarray:
     out = pl.pallas_call(
         kernel,
         grid=(mp // bm,),
-        in_specs=[pl.BlockSpec((bm, np_), lambda k: (k, 0))],
-        out_specs=pl.BlockSpec((np_, np_), lambda k: (0, 0)),
+        in_specs=[pl.BlockSpec((bm, np_), lambda k: (k, _ZERO))],
+        out_specs=pl.BlockSpec((np_, np_), lambda k: (_ZERO, _ZERO)),
         out_shape=jax.ShapeDtypeStruct((np_, np_), a.dtype),
         scratch_shapes=[pltpu.VMEM((np_, np_), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(a)

@@ -26,9 +26,14 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
+# Block index of an untiled axis.  A bare ``0`` traces as int64 once x64 is
+# enabled (``import repro.core`` enables it), and Mosaic rejects an index map
+# that returns mixed int32/int64 indices.
+_ZERO = np.int32(0)
 
 
 def _tall_apply_kernel(a_ref, b_ref, o_ref, *, compute):
@@ -66,12 +71,12 @@ def _tall_apply(a: jnp.ndarray, b: jnp.ndarray, bm: int, interpret: bool,
         kernel,
         grid=(mp // bm,),
         in_specs=[
-            pl.BlockSpec((bm, kp), lambda i: (i, 0)),
-            pl.BlockSpec((kp, np_), lambda i: (0, 0)),
+            pl.BlockSpec((bm, kp), lambda i: (i, _ZERO)),
+            pl.BlockSpec((kp, np_), lambda i: (_ZERO, _ZERO)),
         ],
-        out_specs=pl.BlockSpec((bm, np_), lambda i: (i, 0)),
+        out_specs=pl.BlockSpec((bm, np_), lambda i: (i, _ZERO)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), out_dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(a_p, b_p)

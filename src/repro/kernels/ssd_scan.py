@@ -17,7 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 
 
 def _ssd_kernel(x_ref, b_ref, c_ref, a_ref, y_ref, h_ref, *, chunk: int):
@@ -82,7 +81,7 @@ def ssd_scan(x: jnp.ndarray, b: jnp.ndarray, c: jnp.ndarray, a: jnp.ndarray,
         out_specs=pl.BlockSpec((1, chunk, p), lambda h, i: (h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, lp, p), x.dtype),
         scratch_shapes=[pltpu.VMEM((n, p), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(x, b, c, a3)

@@ -5,38 +5,24 @@ touches jax device state.  The single-pod mesh is 16x16 = 256 chips
 (data, model); the multi-pod mesh adds a leading ``pod`` axis:
 (2, 16, 16) = 512 chips.  The ``pod`` axis is pure data parallelism with
 one (optionally compressed) cross-pod gradient all-reduce per step.
-
-This module also hosts the version-compat :func:`shard_map` wrapper (shared
-by the LM stack in :mod:`repro.models` and the SPMD contraction superstep in
-:mod:`repro.core.spmd`) — it lives here because ``launch.mesh`` depends only
-on jax, so both sides can import it without a cycle.
 """
 from __future__ import annotations
 
 import jax
 
-# jax.sharding.AxisType (and the axis_types= kwarg of jax.make_mesh) only
-# exist in newer JAX releases; older versions build the same Auto-typed mesh
-# with no kwarg at all.
-_AXIS_TYPE = getattr(jax.sharding, "AxisType", None)
-
-
-def _axis_type_kwargs(n_axes: int) -> dict:
-    if _AXIS_TYPE is None:
-        return {}
-    return {"axis_types": (_AXIS_TYPE.Auto,) * n_axes}
+_AUTO = jax.sharding.AxisType.Auto
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape, axes):
     """General mesh helper with Auto axis types (tests, elastic restarts)."""
     return jax.make_mesh(tuple(shape), tuple(axes),
-                         **_axis_type_kwargs(len(axes)))
+                         axis_types=(_AUTO,) * len(axes))
 
 
 def make_host_mesh():
@@ -81,54 +67,10 @@ def col_mesh(devices):
     return jax.sharding.Mesh(arr, ("col",))
 
 
-def shard_map(fn, *, mesh, in_specs, out_specs, axis_names=None,
-              check_vma=False):
-    """Version-compat ``shard_map`` (new ``jax.shard_map`` keyword API).
-
-    Older JAX only has ``jax.experimental.shard_map.shard_map`` whose
-    ``auto=`` is the complement of ``axis_names`` and whose replication
-    check is spelled ``check_rep``.
-    """
-    jsm = getattr(jax, "shard_map", None)
-    if jsm is not None:
-        kwargs = {"check_vma": check_vma}
-        if axis_names is not None:
-            kwargs["axis_names"] = axis_names
-        return jsm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   **kwargs)
-    from jax.experimental.shard_map import shard_map as legacy_shard_map
-    # Legacy partial-auto lowering is fragile (XLA aborts on
-    # IsManualSubgroup for common bodies), so go manual over ALL axes:
-    # numerically identical, at the cost of compute replicated over the
-    # would-be-auto axes — acceptable on the small compat meshes.
-    if axis_names is not None and frozenset(axis_names) != frozenset(
-            mesh.axis_names):
-        import warnings
-        auto = sorted(frozenset(mesh.axis_names) - frozenset(axis_names))
-        warnings.warn(
-            f"legacy JAX shard_map fallback: going manual over ALL of "
-            f"{mesh.axis_names} (requested manual={sorted(axis_names)}); "
-            f"compute will be REPLICATED over {auto} — fine on small "
-            f"compat meshes, a blowup on production meshes.",
-            stacklevel=2)
-    return legacy_shard_map(fn, mesh=mesh, in_specs=in_specs,
-                            out_specs=out_specs, check_rep=check_vma,
-                            auto=frozenset())
-
-
-def use_mesh(mesh):
-    """Context manager installing ``mesh`` as the ambient mesh.
-
-    Newer JAX spells this ``jax.set_mesh(mesh)``; on older releases the
-    ``Mesh`` object itself is the context manager.
-    """
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh
-
-
-# TPU v5e-class hardware constants used by the roofline (assignment §ROOFLINE)
+# Published peaks of one TPU v5e chip (Google Cloud documentation).  The
+# production meshes above are v5e pods, and the dry-run roofline models that
+# hardware from compiled programs (on stand-in devices); no code reads these
+# as the peaks of the device it runs on.
 PEAK_FLOPS_BF16 = 197e12      # per chip
 HBM_BW = 819e9                # bytes/s per chip
 ICI_BW = 50e9                 # bytes/s per link

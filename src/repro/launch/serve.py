@@ -26,9 +26,11 @@ import argparse
 import threading
 import time
 
+import jax.numpy as jnp
 import numpy as np
 
 from repro.core import bmps as B
+from repro.core import planner
 from repro.core.circuits import apply_circuit_exact_peps, random_circuit
 from repro.core.einsumsvd import DirectSVD, RandomizedSVD
 from repro.core.observable import Observable
@@ -45,13 +47,14 @@ def _percentiles(lat_s):
             f"p99={pick(0.99):.2f}ms")
 
 
-def build_states(n_states: int, grid: int, layers: int, seed: int = 7):
+def build_states(n_states: int, grid: int, layers: int, seed: int = 7,
+                 dtype=jnp.complex128):
     """RQC-evolve ``n_states`` hot PEPS states (exact evolution, bond 4^(layers/4))."""
     states = []
     for s in range(n_states):
         circ = random_circuit(grid, grid, layers, seed=seed + s)
         states.append(apply_circuit_exact_peps(
-            computational_zeros(grid, grid), circ))
+            computational_zeros(grid, grid, dtype=dtype), circ))
     return states
 
 
@@ -81,14 +84,19 @@ def main(argv=None):
     ap.add_argument("--baseline-queries", type=int, default=8,
                     help="cold per-query contractions for the speedup line")
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--dtype", choices=("complex128", "complex64"),
+                    default="complex128",
+                    help="site-tensor dtype (TPUs run complex64 only)")
     args = ap.parse_args(argv)
+    planner.enable_compile_cache()
 
     svd = (DirectSVD() if args.svd == "direct" else
            RandomizedSVD(niter=4, oversample=8))
     option = B.BMPS(args.chi, svd, engine=args.engine)
 
     t0 = time.perf_counter()
-    states = build_states(args.states, args.grid, args.layers)
+    states = build_states(args.states, args.grid, args.layers,
+                          dtype=jnp.dtype(args.dtype))
     print(f"[serve] {args.states} x {args.grid}x{args.grid} RQC states "
           f"(bond {states[0].max_bond()}) evolved in "
           f"{time.perf_counter()-t0:.1f}s")

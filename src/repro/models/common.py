@@ -17,11 +17,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-# Version-compat shard_map lives with the mesh utilities so the PEPS SPMD
-# superstep (repro.core.spmd) can share it without importing the LM stack;
-# re-exported here because every models/ call site historically uses it.
-from repro.launch.mesh import shard_map  # noqa: F401
-
 
 # ---------------------------------------------------------------------------
 # Param specs
@@ -138,21 +133,9 @@ def param_shardings(specs, mesh: Mesh, rules=None):
 
 def manual_axes() -> set:
     """Mesh axes currently under manual control (inside a shard_map)."""
-    try:
-        am = jax.sharding.get_abstract_mesh()
-        if am is None or not am.axis_names:
-            return set()
-        return {n for n, t in zip(am.axis_names, am.axis_types)
-                if "Manual" in str(t)}
-    except Exception:  # noqa: BLE001 — old JAX / no tracing context
-        pass
-    # Legacy JAX: inside shard_map the manual axes are exactly the named
-    # axes bound in the axis environment.
-    try:
-        from jax._src import core as _core
-        return set(_core.get_axis_env().axis_sizes)
-    except Exception:  # noqa: BLE001
-        return set()
+    am = jax.sharding.get_abstract_mesh()
+    return {n for n, t in zip(am.axis_names, am.axis_types)
+            if t == jax.sharding.AxisType.Manual}
 
 
 def batch_axes(mesh: Mesh) -> Tuple[str, ...]:
@@ -162,15 +145,7 @@ def batch_axes(mesh: Mesh) -> Tuple[str, ...]:
 
 
 def sharding_constraint(x: jnp.ndarray, mesh: Mesh, spec) -> jnp.ndarray:
-    """``with_sharding_constraint`` that degrades to a no-op where unsafe.
-
-    On legacy JAX (no ``jax.shard_map``), emitting a full-mesh sharding
-    constraint inside a partial-auto shard_map trips XLA's
-    ``IsManualSubgroup`` check and aborts compilation; the constraint is
-    only a placement hint, so inside legacy manual regions we drop it.
-    """
-    if manual_axes() and not hasattr(jax, "shard_map"):
-        return x
+    """``with_sharding_constraint`` of ``x`` to ``spec`` on ``mesh``."""
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
 
 

@@ -13,7 +13,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.models.common import (Config, DEFAULT_RULES, abstract_params,
-                                 shard_map,
                                  batch_axes, init_params, param_shardings,
                                  resolve_spec)
 from repro.models import encdec as encdec_mod
@@ -138,7 +137,7 @@ class ModelBundle:
         bspec = jax.tree_util.tree_map(lambda _: P("pod"), batch)
         out_specs = (rep, rep_opt, rep_err,
                      {"loss": P(), "grad_norm": P()})
-        return shard_map(per_pod, mesh=self.mesh,
+        return jax.shard_map(per_pod, mesh=self.mesh,
                              in_specs=(rep, rep_opt, rep_err, bspec),
                              out_specs=out_specs, axis_names={"pod"},
                              check_vma=False)(params, opt_state, err_state,

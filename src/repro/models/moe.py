@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.models.common import Config, P_, batch_axes, shard_map
+from repro.models.common import Config, P_, batch_axes
 
 
 def moe_specs(cfg: Config, n_layers: int) -> Dict[str, P_]:
@@ -160,6 +160,6 @@ def moe_apply(x, p, cfg: Config, mesh) -> jnp.ndarray:
         )
     fn = functools.partial(_moe_local, cfg=cfg, e_loc=e_loc, capacity=capacity,
                            has_model_axis=has_model, fsdp_axes=fsdp_axes)
-    return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=x_spec,
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=x_spec,
                          check_vma=False)(x, p["router"], p["wg"], p["wu"],
                                           p["wd"])

@@ -46,11 +46,7 @@ def psum_compressed(g: jnp.ndarray, err: jnp.ndarray, axis_name: str
     A scalar max-|g| all-reduce first agrees on a SHARED scale, so the int8
     psum dequantizes exactly (up to rounding, which error feedback absorbs).
     Payload over the slow inter-pod link: 1 byte/grad instead of 2-4."""
-    # jax.lax.axis_size is recent; psum(1) is the portable spelling.
-    if hasattr(jax.lax, "axis_size"):
-        n = jax.lax.axis_size(axis_name)
-    else:
-        n = jax.lax.psum(1, axis_name)
+    n = jax.lax.axis_size(axis_name)
     corrected = g.astype(jnp.float32) + err
     local_max = jnp.max(jnp.abs(corrected))
     scale = jax.lax.pmax(local_max, axis_name) / 127.0 + 1e-12

@@ -118,6 +118,31 @@ def test_full_update_ite_matches_statevector(nrow, ncol):
     assert 0.9 <= res.fidelities[-1] <= 1.0 + 1e-9
 
 
+def test_full_update_ite_complex64_matches_statevector():
+    """Full-update ITE in complex64 stays finite and on the exact energy.
+
+    Regression: the ALS ridge (``als_eps=1e-12`` of trace/d) vanished
+    against complex64 rounding, and the rank-deficient normal matrices of
+    the first product-state bonds solved to NaN.  Tolerance: the
+    complex128 run's own error plus the documented complex64 budget per
+    full-update step."""
+    from repro.core.observable import j1j2_hamiltonian
+    from repro.core.precision import error_budget
+    obs = j1j2_hamiltonian(2, 3)
+    steps, tau = 2, 0.05
+    _, e_ref = ite_statevector(2, 3, obs, tau=tau, steps=steps)
+    update = FullUpdate(rank=2, chi=8, env_refresh_every=len(obs))
+    errs = {}
+    for dtype in (jnp.complex128, jnp.complex64):
+        res = ite_run(P.computational_zeros(2, 3, dtype=dtype), obs, tau=tau,
+                      steps=steps, update=update, contract=B.BMPS(8),
+                      measure_every=steps)
+        errs[dtype] = abs(res.energies[-1] - e_ref) / abs(e_ref)
+    tol = errs[jnp.complex128] + steps * error_budget("full_update_ite_step",
+                                                      "mixed")
+    assert errs[jnp.complex64] <= tol, errs
+
+
 def test_full_update_beats_simple_update_at_fixed_bond():
     """At equal bond dimension and Trotter steps, the environment-aware
     update must reach a strictly lower energy error (2x3 TFI, D=2)."""

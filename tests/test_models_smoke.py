@@ -6,7 +6,7 @@ import numpy as np
 import pytest
 
 from repro.configs import ARCHS, get, get_smoke
-from repro.launch.mesh import make_host_mesh, use_mesh
+from repro.launch.mesh import make_host_mesh
 from repro.models.model import build
 from repro.optim.adamw import adamw_init
 
@@ -36,7 +36,7 @@ def test_smoke_train_step(arch):
     params = bundle.init(jax.random.PRNGKey(1))
     opt = adamw_init(params)
     batch = _batch(cfg)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         step = jax.jit(bundle.train_step)
         new_params, new_opt, metrics = step(params, opt, batch)
     loss = float(metrics["loss"])
@@ -56,7 +56,7 @@ def test_smoke_decode_step(arch):
     bundle = build(cfg, mesh)
     params = bundle.init(jax.random.PRNGKey(2))
     b, max_seq = 2, 32
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         cache = bundle.init_cache(b, max_seq)
         if cfg.family == "encdec":
             # fill cross-attention cache with zeros (already zeros)
@@ -81,7 +81,7 @@ def test_smoke_prefill(arch):
     bundle = build(cfg, mesh)
     params = bundle.init(jax.random.PRNGKey(3))
     tokens = jnp.zeros((2, 16), jnp.int32)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         logits, cache = jax.jit(bundle.prefill_step)(params, tokens)
     assert logits.shape == (2, cfg.vocab)
     assert np.isfinite(np.asarray(logits, np.float32)).all()
@@ -97,7 +97,7 @@ def test_prefill_then_decode_consistent(arch):
     bundle = build(cfg, mesh)
     params = bundle.init(jax.random.PRNGKey(4))
     toks = jax.random.randint(jax.random.PRNGKey(5), (2, 16), 0, cfg.vocab)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         logits_full, _ = jax.jit(bundle.prefill_step)(params, toks)
         _, cache = jax.jit(bundle.prefill_step)(params, toks[:, :15])
         if cfg.family == "hybrid":  # widen shared-attn kv cache to >=16

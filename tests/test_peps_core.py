@@ -85,6 +85,14 @@ def test_random_peps_shapes():
     assert st.max_bond() == 3
 
 
+@pytest.mark.parametrize("dtype", ["complex64", "complex128", "float32"])
+def test_random_peps_honours_dtype(dtype):
+    """Every site comes out in the requested dtype (the per-site norm is a
+    numpy float64 and used to promote complex64 sites to complex128)."""
+    s = P.random_peps(2, 3, 2, jax.random.PRNGKey(1), dtype=dtype)
+    assert {t.dtype for row in s.sites for t in row} == {jnp.dtype(dtype)}
+
+
 def test_peps_is_pytree():
     st = P.random_peps(2, 2, 2, jax.random.PRNGKey(0))
     leaves = jax.tree_util.tree_leaves(st)

@@ -125,6 +125,82 @@ def test_served_randomized_svd(state33):
                       _direct(state33, bits, option))
 
 
+def test_served_complex64_randomized_vs_statevector():
+    """complex64 served == direct == statevector with the randomized SVD.
+
+    Regression: the Gram-QR eigenvalue clamp had an absolute floor
+    (``1e-6 * max(|lam|, 1)`` in complex64), so every boundary whose Gram
+    spectrum sat below 1 was truncated to noise; on this 4x4 depth-4 RQC
+    (bond 4, chi=16 is exact) served and direct amplitudes differed by a
+    relative 1e7.  The tolerance is the complex128 run's error (~1e-14)
+    plus the documented complex64 amplitude budget."""
+    from repro.core import statevector as sv
+    from repro.core.circuits import (apply_circuit_exact_peps,
+                                     apply_circuit_statevector,
+                                     random_circuit)
+    from repro.core.precision import error_budget
+    circ = random_circuit(4, 4, 4, seed=7)
+    state = apply_circuit_exact_peps(
+        P.computational_zeros(4, 4, dtype=jnp.complex64), circ)
+    vec = np.asarray(apply_circuit_statevector(sv.zeros(16), circ))
+    bits = _bits(state, 6)
+    exact = np.array([vec[tuple(b.reshape(-1))] for b in bits])
+    option = B.BMPS(16, RandomizedSVD())
+    with ServingEngine(start=False) as engine:
+        engine.register_state("s", state, option)
+        served = np.asarray(engine.amplitude_batch("s", bits))
+    direct = _direct(state, bits, option)
+    tol = 1e-13 + error_budget("amplitude", "mixed")
+    rel = lambda a, b: np.linalg.norm(a - b) / np.linalg.norm(b)
+    assert rel(served, direct) <= tol
+    assert rel(served, exact) <= tol
+    assert rel(direct, exact) <= tol
+
+
+def test_served_complex64_with_the_tpus_svd_vs_statevector(monkeypatch):
+    """complex64 served == direct == statevector with the SVD a TPU runs
+    (``jnp.linalg.svd`` there is a QDWH polar factor, then eigh), executed
+    here on the CPU through JAX's own implementation of it.
+
+    Regression: that SVD pairs its left and right vectors loosely, so
+    ``u diag(s) vh`` misses its input by up to ~5e-6 in complex64, and the
+    served amplitudes of the ``repro.launch.serve`` default state (4x4
+    depth-8 RQC, exact chi=256) came out 3.3e-5 off the statevector here
+    and 2.1e-5 on a v5e, against 2.8e-6 with LAPACK.  ``DirectSVD`` now
+    takes ``s`` and ``vh`` from ``u^H A``.  The tolerance is the complex128
+    run's error (~1e-14) plus the documented complex64 amplitude budget."""
+    import importlib
+
+    from jax._src.tpu.linalg import svd as tpu_svd
+
+    from repro.core import statevector as sv
+    from repro.core.circuits import apply_circuit_statevector, random_circuit
+    from repro.core.precision import error_budget
+    from repro.launch.serve import build_states
+    esvd = importlib.import_module("repro.core.einsumsvd")
+    state = build_states(1, 4, 8, seed=7, dtype=jnp.complex64)[0]
+    vec = np.asarray(apply_circuit_statevector(
+        sv.zeros(16), random_circuit(4, 4, 8, seed=7)))
+    bits = _bits(state, 6)
+    exact = np.array([vec[tuple(b.reshape(-1))] for b in bits])
+    option = B.BMPS(256)
+    monkeypatch.setattr(esvd, "svd_reg",
+                        lambda m: tpu_svd.svd(m, full_matrices=False))
+    esvd._truncated_svd.clear_cache()
+    try:
+        with ServingEngine(start=False) as engine:
+            engine.register_state("s", state, option)
+            served = np.asarray(engine.amplitude_batch("s", bits))
+        direct = _direct(state, bits, option)
+    finally:
+        esvd._truncated_svd.clear_cache()
+    tol = 1e-13 + error_budget("amplitude", "mixed")
+    rel = lambda a, b: np.linalg.norm(a - b) / np.linalg.norm(b)
+    assert rel(served, direct) <= tol
+    assert rel(served, exact) <= tol
+    assert rel(direct, exact) <= tol
+
+
 @pytest.mark.parametrize("n", [1, 5, 150])
 def test_served_ragged_batch_sizes(state33, n):
     # 1 (smallest bucket), 5 (not a bucket multiple), 150 (> largest bucket)
